@@ -1,13 +1,9 @@
-"""jax version-compat shims for mesh construction and mesh contexts.
+"""Mesh construction for the whole repo.
 
-The repo targets the modern explicit-axis-type API (``jax.make_mesh(...,
-axis_types=(AxisType.Auto, ...))`` + ``jax.set_mesh``), but the pinned
-container jax (0.4.x) predates both ``jax.sharding.AxisType`` and
-``jax.set_mesh``.  Everything that builds or enters a mesh goes through
-these two helpers so the same code runs on either API:
-
-  make_compat_mesh(shape, axis_names)   -> Mesh (Auto axes when supported)
-  use_mesh(mesh)                        -> context manager for the mesh
+``jax.make_mesh`` gives Explicit axes by default; the plans here are
+applied as ``with_sharding_constraint`` hints that GSPMD propagates, which
+needs Auto axes.  Every mesh is built through this helper so the axis type
+is decided in one place; enter one with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
@@ -19,26 +15,7 @@ import jax
 def make_compat_mesh(axis_shapes: Sequence[int],
                      axis_names: Sequence[str],
                      *, devices: Optional[Sequence] = None):
-    """``jax.make_mesh`` with ``AxisType.Auto`` axes where the installed
-    jax supports them, plain mesh otherwise (pre-0.5 jax has neither
-    ``jax.sharding.AxisType`` nor the ``axis_types`` kwarg; a plain Mesh
-    there behaves like all-Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names), devices=devices,
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)))
-        except TypeError:
-            pass  # AxisType exists but make_mesh predates the kwarg
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on jax
-    versions that have it, else the Mesh object itself (the classic
-    ``with mesh:`` context)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))

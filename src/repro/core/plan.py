@@ -9,9 +9,7 @@ same name stack into a tuple (PartitionSpec allows that).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
-
-from jax.sharding import PartitionSpec as P
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 from .solver import TilingSolution
 from .tiling import Part, REPLICATE
@@ -20,6 +18,9 @@ from .tiling import Part, REPLICATE
 # CACHE_RULES maps the cache leaves onto them); the serving engine shards
 # the pool cache through these
 CACHE_ROLES = ("kv_cache", "ssm_state", "block_table")
+
+if TYPE_CHECKING:
+    from jax.sharding import PartitionSpec as P
 
 
 @dataclasses.dataclass
@@ -62,6 +63,9 @@ class ShardingPlan:
         replicated (``P()``) when no default is given.  Callers that need
         to *distinguish* an unknown role (e.g. to skip a sharding
         constraint entirely) should check :meth:`has_role` first."""
+        # imported here, not at module level: the solver's worker
+        # processes load repro.core and must never import JAX
+        from jax.sharding import PartitionSpec as P
         cuts = self.role_cuts.get(role)
         if cuts is None:
             return P() if default is None else default

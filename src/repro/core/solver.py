@@ -408,6 +408,17 @@ def _solve_one_cut_seed(g: Graph, arity: int,
     return OneCutSolution(best_cost + base_cost, full)
 
 
+def _process_pool(workers: int):
+    """Solver worker pool.  Spawned, never forked: the parent may hold an
+    accelerator (and JAX's threads), which a forked child would inherit.
+    A spawned child imports only the solver, and ``repro.core`` imports
+    no JAX, so the child never touches the device."""
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+    return ProcessPoolExecutor(max_workers=workers,
+                               mp_context=multiprocessing.get_context("spawn"))
+
+
 def _bruteforce_chunk(payload) -> Tuple[float, Optional[Assignment]]:
     """Worker for the parallel oracle: exhaust the sub-product where the
     pivot tensor is pinned to one choice (top-level for pickling)."""
@@ -459,10 +470,8 @@ def _solve_one_cut_bruteforce(g: Graph, arity: int,
             sub[pivot] = [c]
             jobs.append((g, arity, names, sub, mem_scale, terms))
         try:
-            from concurrent.futures import ProcessPoolExecutor
             from concurrent.futures.process import BrokenProcessPool
-            with ProcessPoolExecutor(
-                    max_workers=min(workers, len(jobs))) as ex:
+            with _process_pool(min(workers, len(jobs))) as ex:
                 results = list(ex.map(_bruteforce_chunk, jobs))
             best = min(results, key=lambda r: r[0])
             assert best[1] is not None
@@ -599,10 +608,8 @@ def solve_mesh_many(jobs: Sequence[Tuple[Graph, Sequence[MeshAxis]]],
     workers = workers if workers is not None else (os.cpu_count() or 1)
     if workers > 1 and len(jobs) > 1:
         try:
-            from concurrent.futures import ProcessPoolExecutor
             from concurrent.futures.process import BrokenProcessPool
-            with ProcessPoolExecutor(
-                    max_workers=min(workers, len(jobs))) as ex:
+            with _process_pool(min(workers, len(jobs))) as ex:
                 return list(ex.map(_solve_mesh_job, payloads))
         except (OSError, BrokenProcessPool):
             pass
@@ -670,10 +677,8 @@ def solve_mesh_capacity(g: Graph, axes: Sequence[MeshAxis],
                      {"beam": beam, "mem_scale": sc, "compute": compute})
                     for sc in scales]
         try:
-            from concurrent.futures import ProcessPoolExecutor
             from concurrent.futures.process import BrokenProcessPool
-            ex = ProcessPoolExecutor(
-                max_workers=min(workers, len(scales)))
+            ex = _process_pool(min(workers, len(scales)))
             try:
                 futs = [ex.submit(_solve_mesh_job, p) for p in payloads]
                 for i, fut in enumerate(futs):

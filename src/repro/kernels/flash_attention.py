@@ -25,14 +25,15 @@ NEG_INF = -1e30
 
 
 def _row_mask(start, block, limit):
-    """[block] bool: which rows of a padded tile are in-bounds."""
-    return start + jax.lax.broadcasted_iota(jnp.int32, (block,), 0) < limit
+    """[block, 1] bool: which rows of a padded tile are in-bounds (a 2-D
+    column, since Mosaic lowers no 1-D iota)."""
+    return start + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0) < limit
 
 
 def _clean(x, valid):
     """Zero padded rows (pallas pads OOB tiles with undefined values;
     0 * NaN = NaN would otherwise poison the accumulators)."""
-    return jnp.where(valid[:, None], x, 0.0)
+    return jnp.where(valid, x, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -73,22 +74,21 @@ def _fwd_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
         mask &= k_pos > q_pos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]                                       # [bq, 1]
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * corr + p.sum(-1)
+    l_new = l_scr[...] * corr + p.sum(-1, keepdims=True)
     v = _clean(v_ref[...].astype(jnp.float32), kvalid)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
-                    + jax.lax.dot(p.astype(v.dtype), v))
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(p, v)
     m_scr[...] = m_new
     l_scr[...] = l_new
 
     @pl.when(ki == nk - 1)
     def _fin():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[...] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[...] + jnp.log(l)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -130,13 +130,15 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
 
     kw = dict(scale=scale, causal=causal, window=window,
               block_q=block_q, block_k=block_k, sq=sq, sk=sk)
+    # lse leaves the kernel as [B, H, Sq, 1]: a squeezed head axis may not
+    # sit second-to-last in a TPU block, a trailing unit axis may.
     out_shapes = (
         jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
-        jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+        jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
     )
     scratch = [
-        pltpu.VMEM((block_q,), jnp.float32),
-        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, hd), jnp.float32),
     ]
     ins = (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -157,14 +159,14 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
             out_specs=(
                 pl.BlockSpec((None, None, block_q, hd),
                              lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
-                pl.BlockSpec((None, None, block_q),
-                             lambda bb, hh, qi, ki: (bb, hh, qi)),
+                pl.BlockSpec((None, None, block_q, 1),
+                             lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
             ),
             scratch_shapes=scratch,
             out_shape=out_shapes,
             interpret=interpret,
         )(*ins)
-        return o.transpose(0, 2, 1, 3), lse
+        return o.transpose(0, 2, 1, 3), lse[..., 0]
 
     off = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -183,8 +185,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
         out_specs=(
             pl.BlockSpec((None, None, block_q, hd),
                          lambda bb, hh, qi, ki, off: (bb, hh, qi, 0)),
-            pl.BlockSpec((None, None, block_q),
-                         lambda bb, hh, qi, ki, off: (bb, hh, qi)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda bb, hh, qi, ki, off: (bb, hh, qi, 0)),
         ),
         scratch_shapes=scratch,
     )
@@ -194,18 +196,24 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
         out_shape=out_shapes,
         interpret=interpret,
     )(off, *ins)
-    return o.transpose(0, 2, 1, 3), lse
+    return o.transpose(0, 2, 1, 3), lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
 # decode (one query token per slot against the serving engine's KV cache)
 # ---------------------------------------------------------------------------
+#
+# The caches keep the kv-head axis second-to-last ([.., tokens, KV, hd]),
+# so a TPU block cannot squeeze it to one head: each grid step takes the
+# whole [block, KV, hd] tile and walks the kv heads in a static loop.
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, window, block_k):
-    bb = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _decode_tile(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 k_start, length, window, scale, kv_heads):
+    """One KV tile of the online softmax for every kv head of one slot.
+    q_ref [KV, g, hd], k_ref/v_ref [bk, KV, hd]; positions >= ``length``
+    (tile padding, recycled or null blocks) never enter the softmax."""
+    ki = pl.program_id(1)
+    bk = k_ref.shape[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -213,33 +221,57 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[bb]
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k,), 0)
-    valid = k_pos < length          # also masks tile padding (length <= S)
-    if window is not None:
-        valid &= k_pos >= length - window
+    def live(shape, dim):
+        pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+        ok = pos < length
+        if window is not None:
+            ok &= pos >= length - window
+        return ok
 
-    q = q_ref[...].astype(jnp.float32) * scale            # [g, hd]
-    k = _clean(k_ref[...].astype(jnp.float32), valid)     # [bk, hd]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [g, bk]
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    col = live((bk, 1), 0)                                 # rows of k/v
+    row = live((1, bk), 1)                                 # columns of s
+    for j in range(kv_heads):
+        q = q_ref[j].astype(jnp.float32) * scale               # [g, hd]
+        k = _clean(k_ref[:, j, :].astype(jnp.float32), col)    # [bk, hd]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [g, bk]
+        s = jnp.where(row, s, NEG_INF)
+        m_prev = m_scr[j]                                      # [g, 1]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[j] = l_scr[j] * corr + p.sum(-1, keepdims=True)
+        v = _clean(v_ref[:, j, :].astype(jnp.float32), col)
+        acc_scr[j] = acc_scr[j] * corr + jax.lax.dot(p, v)
+        m_scr[j] = m_new
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * corr + p.sum(-1)
-    v = _clean(v_ref[...].astype(jnp.float32), valid)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
-                    + jax.lax.dot(p.astype(v.dtype), v))
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _fin():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scr, **kw):
+    bb = pl.program_id(0)
+    _decode_tile(q_ref, k_ref, v_ref, o_ref, *scr,
+                 k_start=pl.program_id(1) * k_ref.shape[0],
+                 length=len_ref[bb], **kw)
+
+
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                         *scr, **kw):
+    # the BlockSpec index_map already used tbl_ref to route this grid
+    # step's (k_ref, v_ref) at the right pool block, so the body only
+    # needs the slot's length
+    bb = pl.program_id(0)
+    _decode_tile(q_ref, k_ref, v_ref, o_ref, *scr,
+                 k_start=pl.program_id(1) * k_ref.shape[0],
+                 length=len_ref[bb], window=None, **kw)
+
+
+def _decode_scratch(kv, g, hd):
+    return [pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, hd), jnp.float32)]
 
 
 def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
@@ -260,26 +292,21 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kv, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((None, None, g, hd),
-                         lambda bb, kvi, ki, L: (bb, kvi, 0, 0)),
-            pl.BlockSpec((None, block_k, None, hd),
-                         lambda bb, kvi, ki, L: (bb, ki, kvi, 0)),
-            pl.BlockSpec((None, block_k, None, hd),
-                         lambda bb, kvi, ki, L: (bb, ki, kvi, 0)),
+            pl.BlockSpec((None, kv, g, hd), lambda bb, ki, L: (bb, 0, 0, 0)),
+            pl.BlockSpec((None, block_k, kv, hd),
+                         lambda bb, ki, L: (bb, ki, 0, 0)),
+            pl.BlockSpec((None, block_k, kv, hd),
+                         lambda bb, ki, L: (bb, ki, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, g, hd),
-                               lambda bb, kvi, ki, L: (bb, kvi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, kv, g, hd),
+                               lambda bb, ki, L: (bb, 0, 0, 0)),
+        scratch_shapes=_decode_scratch(kv, g, hd),
     )
     o = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, window=window,
-                          block_k=block_k),
+                          kv_heads=kv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
@@ -290,51 +317,6 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
 # ---------------------------------------------------------------------------
 # paged decode (block-pool KV cache gathered through a block table)
 # ---------------------------------------------------------------------------
-
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, block_len):
-    # tbl_ref / len_ref are scalar-prefetch refs: the BlockSpec index_map
-    # already used tbl_ref to route this grid step's (k_ref, v_ref) at
-    # the right pool block, so the body only needs the slot's length.
-    bb = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[bb]
-    k_pos = ki * block_len + jax.lax.broadcasted_iota(
-        jnp.int32, (block_len,), 0)
-    # positions >= length are dead: stale data from retired requests'
-    # recycled blocks, or the reserved null block behind an unallocated
-    # table entry — NEG_INF'd exactly like the linear decode kernel
-    valid = k_pos < length
-
-    q = q_ref[...].astype(jnp.float32) * scale            # [g, hd]
-    k = _clean(k_ref[...].astype(jnp.float32), valid)     # [bl, hd]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [g, bl]
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * corr + p.sum(-1)
-    v = _clean(v_ref[...].astype(jnp.float32), valid)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
-                    + jax.lax.dot(p.astype(v.dtype), v))
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-
-    @pl.when(ki == nk - 1)
-    def _fin():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-
 
 def flash_attention_paged_decode(q, k_pool, v_pool, table, lengths, *,
                                  scale=None, interpret=False):
@@ -355,29 +337,21 @@ def flash_attention_paged_decode(q, k_pool, v_pool, table, lengths, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, mb),
+        grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((None, None, g, hd),
-                         lambda bb, kvi, ki, tbl, L: (bb, kvi, 0, 0)),
-            pl.BlockSpec((None, bl, None, hd),
-                         lambda bb, kvi, ki, tbl, L:
-                         (tbl[bb, ki], 0, kvi, 0)),
-            pl.BlockSpec((None, bl, None, hd),
-                         lambda bb, kvi, ki, tbl, L:
-                         (tbl[bb, ki], 0, kvi, 0)),
+            pl.BlockSpec((None, kv, g, hd),
+                         lambda bb, ki, tbl, L: (bb, 0, 0, 0)),
+            pl.BlockSpec((None, bl, kv, hd),
+                         lambda bb, ki, tbl, L: (tbl[bb, ki], 0, 0, 0)),
+            pl.BlockSpec((None, bl, kv, hd),
+                         lambda bb, ki, tbl, L: (tbl[bb, ki], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, g, hd),
-                               lambda bb, kvi, ki, tbl, L:
-                               (bb, kvi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, kv, g, hd),
+                               lambda bb, ki, tbl, L: (bb, 0, 0, 0)),
+        scratch_shapes=_decode_scratch(kv, g, hd),
     )
     o = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale,
-                          block_len=bl),
+        functools.partial(_paged_decode_kernel, scale=scale, kv_heads=kv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
@@ -415,11 +389,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask &= k_pos <= q_pos
     if window is not None:
         mask &= k_pos > q_pos - window
-    p = jnp.where(mask, jnp.exp(s - lse_ref[...][:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_ref[...]), 0.0)
     do = _clean(do_ref[...].astype(jnp.float32), qvalid)
     v = _clean(v_ref[...].astype(jnp.float32), kvalid)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[...][:, None])
+    ds = p * (dp - delta_ref[...])
     dq_scr[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(ki == nk - 1)
@@ -454,11 +428,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask &= k_pos <= q_pos
     if window is not None:
         mask &= k_pos > q_pos - window
-    p = jnp.where(mask, jnp.exp(s - lse_ref[...][:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_ref[...]), 0.0)
     do = _clean(do_ref[...].astype(jnp.float32), qvalid)
     v = _clean(v_ref[...].astype(jnp.float32), kvalid)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[...][:, None])
+    ds = p * (dp - delta_ref[...])
     dk_scr[...] += jax.lax.dot(ds.T, qraw) * scale
     dv_scr[...] += jax.lax.dot(p.T, do)
 
@@ -480,8 +454,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
     block_k = min(block_k, sk)
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
+    # lse/delta enter as [B, H, Sq, 1] (the forward's kernel layout)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)          # [B,H,Sq]
+                    axis=-1).transpose(0, 2, 1)[..., None]
+    lse = lse[..., None]
 
     qT = q.transpose(0, 2, 1, 3)
     kT = k.transpose(0, 2, 1, 3)
@@ -502,10 +478,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                          lambda bb, hh, qi, ki, g=g: (bb, hh // g, ki, 0)),
             pl.BlockSpec((None, None, block_q, hd),
                          lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
-            pl.BlockSpec((None, None, block_q),
-                         lambda bb, hh, qi, ki: (bb, hh, qi)),
-            pl.BlockSpec((None, None, block_q),
-                         lambda bb, hh, qi, ki: (bb, hh, qi)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, block_q, hd),
                                lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
@@ -530,10 +506,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                          lambda bb, hh, ki, qi: (bb, hh, ki, 0)),
             pl.BlockSpec((None, None, block_q, hd),
                          lambda bb, hh, ki, qi: (bb, hh, qi, 0)),
-            pl.BlockSpec((None, None, block_q),
-                         lambda bb, hh, ki, qi: (bb, hh, qi)),
-            pl.BlockSpec((None, None, block_q),
-                         lambda bb, hh, ki, qi: (bb, hh, qi)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda bb, hh, ki, qi: (bb, hh, qi, 0)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda bb, hh, ki, qi: (bb, hh, qi, 0)),
         ],
         out_specs=(
             pl.BlockSpec((None, None, block_k, hd),

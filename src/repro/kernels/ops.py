@@ -1,14 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
 `flash_attention` carries a custom_vjp wired to the Pallas backward
-kernels.  On this CPU container the kernels execute in interpret mode
-(Pallas-TPU cannot compile to CPU); on a real TPU interpret=False.
+kernels.  On a CPU backend the kernels execute in interpret mode
+(Pallas-TPU cannot compile to CPU); on a TPU they always run compiled.
 
 The mode is resolved ONCE (cached) so every call in a compiled program
-agrees, and `REPRO_PALLAS_INTERPRET` overrides the backend heuristic
-(=1 forces interpret, =0 forces compiled) — TPU CI and the CPU container
-both get a deterministic mode.  Tests that flip the env var must call
-``_default_interpret.cache_clear()``.
+agrees.  `REPRO_PALLAS_INTERPRET=0` forces compiled kernels on a CPU
+host, for ahead-of-time compiles against a described TPU topology;
+asking for interpret mode (=1) on a TPU backend is an error, so no run
+on the chip can fall back to the interpreter.  Tests that flip the env
+var must call ``_default_interpret.cache_clear()``.
 """
 from __future__ import annotations
 
@@ -29,11 +30,16 @@ _FALSY = ("0", "false", "no", "off")
 @functools.lru_cache(maxsize=None)
 def _default_interpret() -> bool:
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
+    on_tpu = jax.default_backend() == "tpu"
     if env in _TRUTHY:
+        if on_tpu:
+            raise RuntimeError(
+                "REPRO_PALLAS_INTERPRET asks for interpret-mode kernels on "
+                "a TPU backend; the kernels run compiled there")
         return True
     if env in _FALSY:
         return False
-    return jax.default_backend() != "tpu"
+    return not on_tpu
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -14,6 +14,8 @@ cells — differential numerics + HLO calibration).
 """
 from __future__ import annotations
 
+import functools
+import hashlib
 import json
 import os
 import time
@@ -22,7 +24,6 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..compat import use_mesh
 from ..configs.base import ArchConfig, ShapeConfig
 from ..core.builders import build_graph
 from ..core.plan import ShardingPlan
@@ -41,9 +42,41 @@ CACHE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 # solver plan with on-disk cache
 # ---------------------------------------------------------------------------
 
-def plan_cache_path(arch: str, shape: str, mesh_name: str) -> str:
+# what a cached plan record depends on besides the call's own arguments:
+# the graph builders, cost model and solver (all of repro/core), the
+# as-executed breakdown projections, and this module's record layout
+_PLAN_SOURCES = ("core", "verify/train_cell.py", "verify/calibration.py",
+                 "launch/compile.py")
+
+
+@functools.lru_cache(maxsize=None)
+def _plan_sources_digest() -> str:
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for rel in _PLAN_SOURCES:
+        path = os.path.join(root, rel)
+        files = ([os.path.join(path, f) for f in sorted(os.listdir(path))
+                  if f.endswith(".py")] if os.path.isdir(path) else [path])
+        for f in files:
+            with open(f, "rb") as fh:
+                h.update(f.encode() + b"\0" + fh.read())
+    return h.hexdigest()
+
+
+def plan_key(*inputs) -> str:
+    """Digest of a plan's inputs (config, shape, solver axes, solve
+    options) and of the sources that turn them into a plan: a record left
+    by other inputs or older code can never be read back."""
+    h = hashlib.sha256(_plan_sources_digest().encode())
+    h.update(repr(inputs).encode())
+    return h.hexdigest()[:16]
+
+
+def plan_cache_path(arch: str, shape: str, mesh_name: str,
+                    key: str) -> str:
     os.makedirs(CACHE_DIR, exist_ok=True)
-    return os.path.join(CACHE_DIR, f"{arch}_{shape}_{mesh_name}.json")
+    return os.path.join(CACHE_DIR,
+                        f"{arch}_{shape}_{mesh_name}_{key}.json")
 
 
 def _executed_breakdown(g, axes, per_axis, kind: str) -> Dict[str, Any]:
@@ -85,7 +118,9 @@ def solve_cell_plan(cfg: ArchConfig, shape: ShapeConfig,
     solved under different compute configs never share an entry."""
     if compute is not None:
         mesh_name = f"{mesh_name}_{compute.token()}"
-    path = plan_cache_path(cfg.name, shape.name, mesh_name)
+    key = plan_key(cfg, shape, list(axes), capacity, beam,
+                   sorted((graph_kwargs or {}).items()), mesh_name)
+    path = plan_cache_path(cfg.name, shape.name, mesh_name, key)
     if use_cache and os.path.exists(path):
         with open(path) as f:
             return json.load(f)
@@ -228,7 +263,7 @@ def compile_step(cfg: ArchConfig, shape: ShapeConfig, plan: ShardingPlan,
     model = LM(cfg, plan=plan, attn_impl=attn_impl, mesh=mesh,
                layer_loop=layer_loop)
     key = jax.random.PRNGKey(0)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_s = jax.eval_shape(model.init, key)
         params_sh = tree_shardings(plan, params_s, mesh)
         if shape.kind == "decode":

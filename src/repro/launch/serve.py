@@ -209,6 +209,8 @@ def main(argv=None) -> int:
     import numpy as np
 
     from .. import obs
+    from ..hostdev import enable_compile_cache
+    enable_compile_cache()
     from ..configs.base import ShapeConfig, get_arch
     from ..models.model import LM
     from ..runtime.serve import ServeConfig, Server

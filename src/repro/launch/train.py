@@ -28,6 +28,10 @@ def build_argparser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="python -m repro.launch.train")
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers, keeping "
+                         "the published widths (sizes a config to the "
+                         "memory of fewer chips)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--warmup", type=int, default=2,
                     help="steps excluded from throughput (jit compiles)")
@@ -110,6 +114,9 @@ def main(argv=None) -> int:
 
     import jax
 
+    from ..hostdev import enable_compile_cache
+    enable_compile_cache()
+
     from .. import obs
     from ..configs.base import ShapeConfig, get_arch
     from ..data.pipeline import BatchFeed, DataConfig
@@ -124,6 +131,9 @@ def main(argv=None) -> int:
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        import dataclasses
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     master_fp32 = not args.no_master_fp32
 
     plan = mesh = None
@@ -353,6 +363,7 @@ def main(argv=None) -> int:
     rec = {
         "meta": {
             "arch": cfg.name, "reduced": args.reduced,
+            "n_layers": cfg.n_layers,
             "batch": args.batch, "seq": args.seq,
             "steps": len(hist), "microbatches": args.microbatches,
             "buckets": args.buckets,

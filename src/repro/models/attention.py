@@ -113,7 +113,6 @@ def attend_cache_pallas(q, k_cache, v_cache, length, *,
 
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, h, hd = q.shape
@@ -129,13 +128,13 @@ def attend_cache_pallas(q, k_cache, v_cache, length, *,
     if not ok:
         return attend_cache(q, k_cache, v_cache, length,
                             window=window, scale=scale)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(kops.flash_attention_decode, window=window, scale=scale),
         mesh=mesh,
         in_specs=(P(bs, hs, None), P(bs, None, hs, None),
                   P(bs, None, hs, None), P(bs)),
         out_specs=P(bs, hs, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, length)
 
 
@@ -186,7 +185,6 @@ def attend_paged_pallas(q, k_pool, v_pool, table, length, *,
 
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, h, hd = q.shape
@@ -203,13 +201,13 @@ def attend_paged_pallas(q, k_pool, v_pool, table, length, *,
                and h % _axes_degree(mesh, hs) == 0))
     if not ok:
         return attend_paged(q, k_pool, v_pool, table, length, scale=scale)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(kops.flash_attention_paged_decode, scale=scale),
         mesh=mesh,
         in_specs=(P(bs, hs, None), P(None, None, hs, None),
                   P(None, None, hs, None), P(bs, None), P(bs)),
         out_specs=P(bs, hs, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, table, length)
 
 

@@ -9,18 +9,17 @@ from jax.sharding import PartitionSpec as P
 
 
 def shard(x, plan, role: str, phys_dims: Sequence[str]):
-    """Apply a solver-derived sharding constraint; no-op without a plan."""
+    """Apply a solver-derived sharding constraint; no-op without a plan or
+    outside a mesh context (``jax.set_mesh``).  Under a mesh, a spec the
+    mesh cannot take is an error, never a silently unsharded tensor."""
     if plan is None:
         return x
     if not plan.has_role(role):
         # unknown role: do NOT constrain (P() would force replication!)
         return x
-    spec = plan.pspec(role, phys_dims)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
-        # outside a mesh context (CPU smoke tests)
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, plan.pspec(role, phys_dims))
 
 
 def rms_norm(x, gamma, eps: float = 1e-5):

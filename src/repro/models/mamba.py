@@ -166,7 +166,6 @@ def _ssd_dispatch(xh, a_log, bb, cc, chunk: int, impl: str,
     if mesh is None or plan is None:
         return _ssd_pallas(xh, a_log, bb, cc, q)[:, :s]
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .attention import _axes_degree, _spec_entries
@@ -174,12 +173,12 @@ def _ssd_dispatch(xh, a_log, bb, cc, chunk: int, impl: str,
     bs = _spec_entries(plan.pspec("ssm_h", ("batch", "seq", "inner")), 3)[0]
     if bs is not None and b % _axes_degree(mesh, bs) != 0:
         bs = None
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x_, a_, b_, c_: _ssd_pallas(x_, a_, b_, c_, q), mesh=mesh,
         in_specs=(P(bs, None, None, None), P(bs, None, None),
                   P(bs, None, None), P(bs, None, None)),
         out_specs=P(bs, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(xh, a_log, bb, cc)[:, :s]
 
 

@@ -43,7 +43,6 @@ def moe_ffn_sharded(params, x, cfg: ArchConfig, plan, mesh
     scatter/gather dispatch (it falls back to replicating the [E·C, D]
     buffer — a 256 GB all-reduce per layer in the 64-expert dry-run, see
     EXPERIMENTS §Perf), so we hand it the local program instead."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     x_spec = plan.pspec("x", ("batch", "seq", "d_model"))
@@ -65,10 +64,10 @@ def moe_ffn_sharded(params, x, cfg: ArchConfig, plan, mesh
         "w_up": up_spec,
         "w_down": plan.pspec("moe_down", ("expert", "e_ff", "d_model")),
     }
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(p_specs, x_spec),
-                   out_specs=(x_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(p_specs, x_spec),
+                       out_specs=(x_spec, P()),
+                       check_vma=False)
     return fn(params, x)
 
 

@@ -35,7 +35,6 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.tracing import span as _span
@@ -131,11 +130,11 @@ def pipeline_forward(mesh: Optional[Mesh], stage_axis: str,
             is_leaf=lambda v: v is None or isinstance(v, P))
     x_full = _join(None, x_spec)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, x_full),
         out_specs=x_full,
-        check_rep=False)
+        check_vma=False)
     outs = fn(params_staged, xm)
     return outs.reshape(b, *x.shape[1:])
 
@@ -322,8 +321,7 @@ class PipelineTrainer:
         fn = self._jit_step()
         with _span("train.pipeline_step", n_stages=self.n_stages):
             if self.mesh is not None:
-                from ..compat import use_mesh
-                with use_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     return fn(state, x, y)
             return fn(state, x, y)
 
@@ -334,7 +332,6 @@ class PipelineTrainer:
         assert self.n_stages > 1
         fn = self._jit_step()
         if self.mesh is not None:
-            from ..compat import use_mesh
-            with use_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 return fn.lower(state_like, x_like, y_like).compile()
         return fn.lower(state_like, x_like, y_like).compile()

@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import use_mesh
 from ..models.model import LM, paged_ok
 from ..obs import metrics as _metrics
 from ..obs.tracing import instant as _instant, span as _span
@@ -369,7 +368,7 @@ class Server:
         return self
 
     def _ctx(self):
-        return use_mesh(self.mesh) if self.mesh is not None \
+        return jax.set_mesh(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
     def _drain(self) -> List[Tuple]:

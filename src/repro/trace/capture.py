@@ -18,7 +18,7 @@ with ``jax.make_jaxpr`` and walks the jaxpr, emitting one semantic op
                             detected automatically), with zero-cost ties
                             for xs slices / ys stacking and an explicit
                             loop-back op pricing carry re-sharding
-  pjit / remat / custom_{jvp,vjp} -> inlined
+  jit / remat / custom_{jvp,vjp} -> inlined
   anything else          -> a conservative ewise fallback (recorded in
                             ``Traced.unknown_primitives``)
 
@@ -236,8 +236,8 @@ class _Capture:
 
     # -- jaxpr walking ---------------------------------------------------
     def read(self, v, env: Dict[Any, _Val]) -> _Val:
-        from jax import core as jcore
-        if isinstance(v, jcore.Literal):
+        from jax.extend.core import Literal
+        if isinstance(v, Literal):
             val = np.asarray(v.val)
             if val.ndim == 0:
                 return _Val(None, (), (), val.dtype)
@@ -247,10 +247,7 @@ class _Capture:
         return env[v]
 
     def bind(self, var, val: _Val, env: Dict[Any, _Val]) -> None:
-        from jax import core as jcore
-        if isinstance(var, jcore.DropVar):
-            return
-        env[var] = val
+        env[var] = val               # a dropped output binds harmlessly
 
     def lower_closed(self, closed, invals: Sequence[_Val],
                      repeat: float) -> List[_Val]:
@@ -733,7 +730,7 @@ class _Capture:
             self.unknown.append("cond")
         return self.lower_closed(branches[0], invals[1:], repeat)
 
-    def _p_pjit(self, eqn, invals, repeat) -> List[_Val]:
+    def _p_jit(self, eqn, invals, repeat) -> List[_Val]:
         return self.lower_closed(eqn.params["jaxpr"], invals, repeat)
 
     def _p_closed_call(self, eqn, invals, repeat) -> List[_Val]:
@@ -743,9 +740,7 @@ class _Capture:
         return self.lower_closed(eqn.params["call_jaxpr"], invals, repeat)
 
     def _p_custom_vjp_call(self, eqn, invals, repeat) -> List[_Val]:
-        return self.lower_closed(eqn.params["fun_jaxpr"], invals, repeat)
-
-    _p_custom_vjp_call_jaxpr = _p_custom_vjp_call
+        return self.lower_closed(eqn.params["call_jaxpr"], invals, repeat)
 
     def _p_remat2(self, eqn, invals, repeat) -> List[_Val]:
         jx = eqn.params["jaxpr"]           # open jaxpr, no consts

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import ckpt
-from ..compat import use_mesh
 from ..models.model import LM
 from ..models.sharding import batch_pspec, tree_pspecs
 from ..obs.tracing import span as _span
@@ -171,7 +170,7 @@ class TrainEngine:
 
     def init_state(self, key) -> PyTree:
         if self.mesh is not None and self.plan is not None:
-            with use_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 sh = self.state_shardings()
                 return jax.jit(self._build_state, out_shardings=sh)(key)
         return self._build_state(key)
@@ -337,14 +336,14 @@ class TrainEngine:
         if self.monitor is None:
             with _span("train.step"):
                 if self.mesh is not None:
-                    with use_mesh(self.mesh):
+                    with jax.set_mesh(self.mesh):
                         return fn(state, batch)
                 return fn(state, batch)
         import time
         t0 = time.monotonic()
         with _span("train.step"):
             if self.mesh is not None:
-                with use_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     out = fn(state, batch)
             else:
                 out = fn(state, batch)
@@ -358,7 +357,7 @@ class TrainEngine:
         allocation) — the conformance cell measures the compiled HLO's
         collectives against ``solution_breakdown`` through this."""
         fn = self._jit_for(tuple(sorted(batch_like.keys())))
-        ctx = use_mesh(self.mesh) if self.mesh is not None else None
+        ctx = jax.set_mesh(self.mesh) if self.mesh is not None else None
         with _span("train.lower_step"):
             if ctx is not None:
                 with ctx:

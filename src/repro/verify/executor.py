@@ -140,7 +140,6 @@ def execute_sharded(g: Graph, values: Dict[str, object],
     import jax
     from jax.sharding import NamedSharding
 
-    from ..compat import use_mesh
 
     outs = outputs if outputs is not None else sink_tensors(g)
     leaves = leaf_tensors(g)
@@ -158,7 +157,7 @@ def execute_sharded(g: Graph, values: Dict[str, object],
         full = execute(g, dict(leaf_vals), constrain=constrain)
         return {t: full[t] for t in outs}
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         placed = {t: jax.device_put(values[t],
                                     NamedSharding(mesh, pspec(t)))
                   for t in leaves}

@@ -46,7 +46,6 @@ def run_numerics(cfg, shape, plan, mesh) -> Dict[str, object]:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from ..compat import use_mesh
     from ..models.model import LM
     from ..models.sharding import (CACHE_RULES, batch_pspec,
                                    tree_shardings)
@@ -58,7 +57,7 @@ def run_numerics(cfg, shape, plan, mesh) -> Dict[str, object]:
     rec: Dict[str, object] = {"kind": shape.kind}
 
     sharded = LM(cfg, plan=plan, mesh=mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         psh = tree_shardings(plan, jax.eval_shape(serial.init, key), mesh)
         p1 = jax.device_put(params, psh)
 

@@ -387,3 +387,19 @@ class TestInterpretOverride:
             assert _default_interpret() is first
         finally:
             _default_interpret.cache_clear()
+
+    def test_interpret_refused_on_tpu(self, monkeypatch):
+        """On a TPU backend the kernels run compiled: asking for the
+        interpreter is an error, not a silent slow path."""
+        from repro.kernels import ops
+        try:
+            monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+            monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+            ops._default_interpret.cache_clear()
+            with pytest.raises(RuntimeError, match="TPU"):
+                ops._default_interpret()
+            monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+            ops._default_interpret.cache_clear()
+            assert ops._default_interpret() is False
+        finally:
+            ops._default_interpret.cache_clear()

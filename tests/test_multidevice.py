@@ -35,7 +35,7 @@ class TestShardedTraining:
     def test_sharded_step_matches_single_device(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.configs import get_arch
             from repro.configs.base import ShapeConfig
@@ -64,7 +64,7 @@ class TestShardedTraining:
 
             # sharded
             m1 = LM(cfg, plan=plan)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 psh = tree_shardings(plan, jax.eval_shape(m1.init, key),
                                      mesh)
                 p1 = jax.jit(m1.init, out_shardings=psh)(key)
@@ -80,7 +80,7 @@ class TestShardedTraining:
     def test_grad_step_sharded_improves_loss(self):
         out = run_py("""
             import jax, jax.numpy as jnp, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from repro.configs import get_arch
             from repro.configs.base import ShapeConfig
             from repro.core.builders import transformer_graph
@@ -101,7 +101,7 @@ class TestShardedTraining:
             model = LM(cfg, plan=plan)
             dcfg = DataConfig(seed=0, vocab=cfg.vocab, seq_len=32,
                               global_batch=8)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 out = train(model, dcfg, TrainConfig(
                     steps=12, optim=AdamWConfig(lr=2e-3, warmup_steps=2)))
             h = out["history"]
@@ -116,7 +116,7 @@ class TestMoEShardMap:
     def test_sharded_moe_matches_local(self):
         out = run_py("""
             import jax, jax.numpy as jnp, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.configs.base import ArchConfig, MoECfg
             from repro.models.moe import init_moe, moe_ffn
@@ -138,7 +138,7 @@ class TestMoEShardMap:
                 "x": {"data": "batch", "model": None},
                 "moe_up": {"data": None, "model": "expert"},
                 "moe_down": {"data": None, "model": "expert"}})
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 xs = jax.device_put(x, NamedSharding(mesh, P("data")))
                 ps = {k: jax.device_put(v, NamedSharding(
                           mesh, P("model") if k.startswith("w_") else P()))
@@ -157,7 +157,7 @@ class TestPipelineParallel:
     def test_pipeline_matches_serial(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from repro.runtime.pipeline_parallel import (
                 make_stage_fn, pipeline_forward, split_stages)
             mesh = make_compat_mesh((4,), ("stage",))
@@ -187,7 +187,7 @@ class TestPipelineParallel:
     def test_pipeline_differentiable(self):
         out = run_py("""
             import jax, jax.numpy as jnp, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from repro.runtime.pipeline_parallel import (
                 make_stage_fn, pipeline_forward, split_stages)
             mesh = make_compat_mesh((2,), ("stage",))
@@ -268,7 +268,7 @@ class TestElasticReshard:
     def test_checkpoint_restores_onto_different_mesh(self, tmp_path):
         out = run_py(f"""
             import jax, jax.numpy as jnp, numpy as np, json
-            from repro.compat import make_compat_mesh, use_mesh
+            from repro.compat import make_compat_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.checkpoint import ckpt
             mesh8 = make_compat_mesh((8,), ("data",))

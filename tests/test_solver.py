@@ -177,6 +177,26 @@ class TestParallelHelpers:
         par = solve_mesh_capacity(g, axes, beam=500, workers=2)
         assert par.total_bytes == pytest.approx(seq.total_bytes)
 
+    def test_workers_are_spawned_and_import_no_jax(self):
+        """A worker must not inherit or open the parent's accelerator: the
+        pool spawns (never forks), and what a worker imports to run a
+        solver job pulls in no JAX."""
+        import os
+        import subprocess
+        import sys
+
+        from repro.core.solver import _process_pool
+        with _process_pool(1) as ex:
+            assert ex._mp_context.get_start_method() == "spawn"
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import sys; import repro.core.solver; "
+             "print('jax' in sys.modules)"],
+            env=dict(os.environ, PYTHONPATH=src), capture_output=True,
+            text=True, check=True).stdout
+        assert out.strip() == "False"
+
 
 class TestMeshSolve:
     def test_monotone_axes(self):
@@ -302,11 +322,40 @@ class TestComputeTerm:
         from repro.core.costterms import ComputeConfig
         from repro.launch import compile as C
         monkeypatch.setattr(C, "CACHE_DIR", str(tmp_path))
-        a = C.plan_cache_path("arch", "shape", "mesh")
+        key = C.plan_key("inputs")
+        a = C.plan_cache_path("arch", "shape", "mesh", key)
         cc = ComputeConfig()
         b = C.plan_cache_path("arch", "shape",
-                              f"mesh_{cc.token()}")
+                              f"mesh_{cc.token()}", key)
         cc2 = ComputeConfig(calibration=0.5)
         c = C.plan_cache_path("arch", "shape",
-                              f"mesh_{cc2.token()}")
-        assert len({a, b, c}) == 3
+                              f"mesh_{cc2.token()}", key)
+        d = C.plan_cache_path("arch", "shape", "mesh",
+                              C.plan_key("other inputs"))
+        assert len({a, b, c, d}) == 4
+
+    def test_plan_cache_ignores_records_of_other_inputs_or_sources(
+            self, tmp_path, monkeypatch):
+        """A record solved for another config or by other solver sources
+        shares the cell's name but never its path, so it is never read."""
+        import dataclasses
+        import json
+
+        from repro.configs import get_arch
+        from repro.configs.base import ShapeConfig
+        from repro.launch import compile as C
+        monkeypatch.setattr(C, "CACHE_DIR", str(tmp_path))
+        cfg = get_arch("qwen2-1.5b").reduced()
+        shape = ShapeConfig("t", 16, 4, "decode")
+        axes = [MeshAxis("data", 2)]
+        rec = C.solve_cell_plan(cfg, shape, axes, "m")
+        (path,) = tmp_path.iterdir()
+        stale = dict(rec, total_bytes=-1.0)
+        path.write_text(json.dumps(stale))
+        assert C.solve_cell_plan(cfg, shape, axes, "m") == stale
+        wider = dataclasses.replace(cfg, d_ff=2 * cfg.d_ff)
+        assert C.solve_cell_plan(wider, shape, axes,
+                                 "m")["total_bytes"] >= 0
+        monkeypatch.setattr(C, "_plan_sources_digest", lambda: "edited")
+        assert C.solve_cell_plan(cfg, shape, axes, "m")["total_bytes"] >= 0
+        assert len(list(tmp_path.iterdir())) == 3

@@ -15,6 +15,41 @@ from repro.runtime.serve import ServeConfig, Server
 from repro.runtime.train_loop import TrainConfig, train
 
 
+def test_sharding_constraint_skipped_only_without_mesh():
+    """Outside a mesh a plan's constraint is a no-op; under one, a spec
+    the mesh cannot take raises instead of leaving the tensor unsharded."""
+    import pytest
+
+    from repro.compat import make_compat_mesh
+    from repro.models.common import shard
+    plan = ShardingPlan(("model",), {"x": {"model": "d_model"}})
+    x = jnp.ones((4, 8))
+    assert shard(x, plan, "x", ("batch", "d_model")) is x
+    mesh = make_compat_mesh((1,), ("data",))
+    with jax.set_mesh(mesh), pytest.raises(Exception, match="model"):
+        jax.jit(lambda v: shard(v, plan, "x", ("batch", "d_model")))(x)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The launchers' compile cache: a fixed ``<checkout>/.cache/jax``, or
+    JAX's own ``JAX_COMPILATION_CACHE_DIR`` left untouched when set."""
+    import os
+
+    from repro import hostdev
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert hostdev.JAX_CACHE_DIR == os.path.join(root, ".cache", "jax")
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert hostdev.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert hostdev.enable_compile_cache() == hostdev.JAX_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == hostdev.JAX_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
 def test_train_end_to_end_loss_decreases():
     cfg = get_arch("llama3.2-3b").reduced()
     model = LM(cfg)
