@@ -1,0 +1,10 @@
+"""The training step's backward pass: device self time per step of the
+ops in the step program under the engine's ``fwd_bwd`` scope whose
+op_name holds ``transpose(``, the recompute of rematerialised layers
+included (bench/scopes.py)."""
+from bench import scopes
+
+
+def read(run):
+    ms = scopes.train_phases(run)
+    return None if ms is None else ms["backward"]

@@ -147,6 +147,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
     if q_offset is None:
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, **kw),
+            name="flash_fwd",
             grid=(b, h, nq, nk),
             in_specs=[
                 pl.BlockSpec((None, None, block_q, hd),
@@ -192,6 +193,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
     )
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel_off, **kw),
+        name="flash_fwd_offset",
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
@@ -307,6 +309,7 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
     o = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, window=window,
                           kv_heads=kv),
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
@@ -352,6 +355,7 @@ def flash_attention_paged_decode(q, k_pool, v_pool, table, lengths, *,
     )
     o = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, kv_heads=kv),
+        name="flash_decode_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
@@ -468,6 +472,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q,
                           block_k=block_k, sq=sq, sk=sk),
+        name="flash_bwd_dq",
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((None, None, block_q, hd),
@@ -496,6 +501,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q,
                           block_k=block_k, sq=sq, sk=sk),
+        name="flash_bwd_dkv",
         grid=(b, kv, nk, nq),
         in_specs=[
             pl.BlockSpec((None, None, block_q, hd),

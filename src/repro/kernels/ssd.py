@@ -80,6 +80,7 @@ def ssd_chunk_scan(xh, a_log, bb, cc, *, chunk: int = 128,
 
     y = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk, nstate=n),
+        name="ssd_chunk_scan",
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((None, None, chunk, p),

@@ -145,13 +145,16 @@ def _attn_forward(p, x, cfg: ArchConfig, positions, plan, impl):
     q = rope(q.reshape(b, s, h, hd), positions, cfg.rope_theta)
     k = rope(k.reshape(b, s, kv, hd), positions, cfg.rope_theta)
     v = v.reshape(b, s, kv, hd)
-    o = attention(q, k, v, causal=True, window=cfg.swa_window, impl=impl)
+    with jax.named_scope("attn"):
+        o = attention(q, k, v, causal=True, window=cfg.swa_window,
+                      impl=impl)
     return o.reshape(b, s, h * hd) @ p["wo"]
 
 
 def _mlp_forward(p, x):
-    g = jax.nn.silu((x @ p["wg"]).astype(jnp.float32)).astype(x.dtype)
-    return (g * (x @ p["wu"])) @ p["wd"]
+    with jax.named_scope("mlp"):
+        g = jax.nn.silu((x @ p["wg"]).astype(jnp.float32)).astype(x.dtype)
+        return (g * (x @ p["wu"])) @ p["wd"]
 
 
 def _dense_layer_forward(p, x, cfg: ArchConfig, positions, plan, impl,
@@ -259,7 +262,8 @@ class LM:
     def _head(self, params, x):
         w = (params["embed"].T if self.cfg.tie_embeddings
              else params["lm_head"])
-        logits = x @ w
+        with jax.named_scope("lm_head"):
+            logits = x @ w
         dims = ("batch", "seq", "vocab") if x.ndim == 3 else ("batch", "vocab")
         return shard(logits, self.plan, "logits", dims)
 
@@ -438,14 +442,18 @@ class LM:
         slot = pos % S if win else pos
         if active is not None:
             slot = jnp.where(active, slot, S)      # OOB -> dropped
-        kc = jax.vmap(lambda c, i, val: c.at[i].set(val, mode="drop"))(
-            kv_cache["k"], slot, k.astype(jnp.bfloat16))
-        vc = jax.vmap(lambda c, i, val: c.at[i].set(val, mode="drop"))(
-            kv_cache["v"], slot, v.astype(jnp.bfloat16))
+        with jax.named_scope("kv_write"):
+            kc = jax.vmap(
+                lambda c, i, val: c.at[i].set(val, mode="drop"))(
+                kv_cache["k"], slot, k.astype(jnp.bfloat16))
+            vc = jax.vmap(
+                lambda c, i, val: c.at[i].set(val, mode="drop"))(
+                kv_cache["v"], slot, v.astype(jnp.bfloat16))
         length = jnp.minimum(pos + 1, kc.shape[1])
-        o = attend_cache(q, kc, vc, length, window=None,
-                         impl=self.attn_impl, mesh=self.mesh,
-                         plan=self.plan)
+        with jax.named_scope("attn"):
+            o = attend_cache(q, kc, vc, length, window=None,
+                             impl=self.attn_impl, mesh=self.mesh,
+                             plan=self.plan)
         return (o.reshape(b, h * hd) @ p["wo"],
                 {"k": kc, "v": vc})
 
@@ -484,14 +492,16 @@ class LM:
         # semantics) before the mode="drop" bounds check, so -1 would
         # scatter into live block NB-1 instead of being dropped
         wblk = jnp.where(ok, blk, nb)              # OOB -> dropped
-        kc = pool["k"].at[wblk, pos % bl].set(k.astype(jnp.bfloat16),
-                                              mode="drop")
-        vc = pool["v"].at[wblk, pos % bl].set(v.astype(jnp.bfloat16),
-                                              mode="drop")
+        with jax.named_scope("kv_write"):
+            kc = pool["k"].at[wblk, pos % bl].set(
+                k.astype(jnp.bfloat16), mode="drop")
+            vc = pool["v"].at[wblk, pos % bl].set(
+                v.astype(jnp.bfloat16), mode="drop")
         length = jnp.minimum(pos + 1, mb * bl)
-        o = attend_paged(q, kc, vc, table, length,
-                         impl=self.attn_impl, mesh=self.mesh,
-                         plan=self.plan)
+        with jax.named_scope("attn"):
+            o = attend_paged(q, kc, vc, table, length,
+                             impl=self.attn_impl, mesh=self.mesh,
+                             plan=self.plan)
         return (o.reshape(b, h * hd) @ p["wo"],
                 {"k": kc, "v": vc})
 
@@ -722,16 +732,18 @@ class LM:
         k = rope(k.reshape(b, c, kvh, hd), positions, cfg.rope_theta)
         v = v.reshape(b, c, kvh, hd)
         idx = positions[0]
-        kc = kv_cache["k"].at[:, idx].set(k.astype(jnp.bfloat16),
-                                          mode="drop")
-        vc = kv_cache["v"].at[:, idx].set(v.astype(jnp.bfloat16),
-                                          mode="drop")
+        with jax.named_scope("kv_write"):
+            kc = kv_cache["k"].at[:, idx].set(k.astype(jnp.bfloat16),
+                                              mode="drop")
+            vc = kv_cache["v"].at[:, idx].set(v.astype(jnp.bfloat16),
+                                              mode="drop")
         # Pallas offset kernel only unsharded: the prefill jit is GSPMD-
         # partitioned when a mesh is present, and pallas_call has no
         # partitioning rule there (decode goes through shard_map instead).
         impl = self.attn_impl if self.mesh is None else "xla"
-        o = attention(q, kc, vc, causal=True, q_offset=positions[0, 0],
-                      impl=impl)
+        with jax.named_scope("attn"):
+            o = attention(q, kc, vc, causal=True,
+                          q_offset=positions[0, 0], impl=impl)
         return o.reshape(b, c, h * hd) @ p["wo"], {"k": kc, "v": vc}
 
     def _prefill_chunk_attn(self, params, sub, tokens, n_valid):
@@ -796,17 +808,19 @@ class LM:
         # the mode="drop" bounds check and would hit live block NB-1
         wblk = jnp.where((jnp.arange(c) < n_valid) & (bidx < mb),
                          blk, nb)                  # OOB -> dropped
-        kc = pool["k"].at[wblk, abs_pos % bl].set(
-            k[0].astype(jnp.bfloat16), mode="drop")
-        vc = pool["v"].at[wblk, abs_pos % bl].set(
-            v[0].astype(jnp.bfloat16), mode="drop")
-        kview = kc[row_table].reshape(1, mb * bl, kvh, hd)
-        vview = vc[row_table].reshape(1, mb * bl, kvh, hd)
+        with jax.named_scope("kv_write"):
+            kc = pool["k"].at[wblk, abs_pos % bl].set(
+                k[0].astype(jnp.bfloat16), mode="drop")
+            vc = pool["v"].at[wblk, abs_pos % bl].set(
+                v[0].astype(jnp.bfloat16), mode="drop")
         # same GSPMD caveat as the linear path: no pallas partitioning
         # rule under a mesh
         impl = self.attn_impl if self.mesh is None else "xla"
-        o = attention(q, kview, vview, causal=True,
-                      q_offset=positions[0, 0], impl=impl)
+        with jax.named_scope("attn"):
+            kview = kc[row_table].reshape(1, mb * bl, kvh, hd)
+            vview = vc[row_table].reshape(1, mb * bl, kvh, hd)
+            o = attention(q, kview, vview, causal=True,
+                          q_offset=positions[0, 0], impl=impl)
         return o.reshape(b, c, h * hd) @ p["wo"], {"k": kc, "v": vc}
 
     def _prefill_chunk_attn_paged(self, params, cache, tokens, slot,

@@ -1,4 +1,5 @@
-"""Structured tracing: span context managers -> Chrome trace-event JSON.
+"""Structured tracing: span context managers -> Chrome trace-event JSON
+and/or the jax profiler's trace.
 
 One process-global :class:`Tracer` records *complete* events ("ph": "X",
 wall-clock microseconds + duration) for ``span(...)`` blocks and
@@ -8,24 +9,26 @@ https://ui.perfetto.dev (File > Open).
 
 Disabled (the default) the hot path is one attribute check returning a
 shared null context manager: no event objects, no timestamps, no
-allocations that survive the call.  Enable explicitly
-(``tracing.enable("run.trace.json")``, what the launch CLIs'
-``--trace-out`` does) or via the ``REPRO_TRACE=<path>`` env var (picked
-up at import; the file is written atexit), which is how subprocess runs
-— conformance cells, benches — inherit tracing.
+allocations that survive the call.  Enable explicitly:
+``tracing.enable("run.trace.json")`` is what the launch CLIs'
+``--trace-out`` does.
 
-``annotate=True`` additionally enters a ``jax.profiler.TraceAnnotation``
-for every span, so spans line up with XLA ops inside a jax profiler
-capture.  jax is imported lazily and only then — this module itself
-stays stdlib-only.
+Sinks (any combination; the hot path checks one ``_active`` attribute
+that folds them together, so the unobserved path stays exactly one
+attribute check regardless of how many exist):
 
-Besides the unbounded export list there is an optional bounded *ring*
-sink (``attach_ring``), which the flight recorder keeps attached for the
-whole run: the last N events are always available for a post-incident
-dump even when ``--trace-out`` was never passed.  The recording hot path
-checks a single ``_active`` attribute that folds together "export list
-enabled" and "ring attached", so the unobserved path stays exactly one
-attribute check regardless of how many sinks exist.
+- the Chrome export list (``enable()`` / ``enable(out)``);
+- the jax profiler (``enable(annotate=True)``): every span enters a
+  ``jax.profiler.TraceAnnotation`` carrying the attributes given when
+  it opens (a list or other container as its length, ``n_<key>``), so
+  spans sit on the profiler's clock beside the device's XLA ops and a
+  profile reader gets them as host events with those stats.  Without
+  ``out`` this sink keeps no Chrome event list.  Attributes added later
+  with ``.set()`` reach the Chrome export only.  jax is imported once,
+  when the sink is enabled — this module itself stays stdlib-only;
+- a bounded *ring* (``attach_ring``), which the flight recorder keeps
+  attached for the whole run: the last N events are always available
+  for a post-incident dump even when ``--trace-out`` was never passed.
 
 Thread-safe: events carry the recording thread's id (Perfetto lays
 threads out as separate tracks) and the event list is appended under a
@@ -59,6 +62,17 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_CONTAINERS = (list, tuple, set, frozenset, dict)
+
+
+def _profiler_attrs(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """A span's attributes as profiler stats: a container as its
+    length under ``n_<key>`` (the profiler keeps scalars and strings)."""
+    return {(f"n_{k}" if isinstance(v, _CONTAINERS) else k):
+            (len(v) if isinstance(v, _CONTAINERS) else v)
+            for k, v in attrs.items()}
+
+
 class _Span:
     __slots__ = ("_tracer", "name", "attrs", "_t0", "_ann")
 
@@ -70,21 +84,20 @@ class _Span:
         self._ann = None
 
     def set(self, **attrs):
-        """Attach/override attributes mid-span (recorded at exit)."""
+        """Attach/override attributes mid-span (recorded at exit, in the
+        Chrome export and the ring; the profiler has the span's opening
+        attributes only)."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
         return self
 
     def __enter__(self):
-        t = self._tracer
-        if t.annotate:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._ann = TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:       # jax absent / profiler unavailable
-                self._ann = None
+        ann = self._tracer.annotation
+        if ann is not None:
+            self._ann = (ann(self.name, **_profiler_attrs(self.attrs))
+                         if self.attrs else ann(self.name))
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -92,7 +105,9 @@ class _Span:
         t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._tracer._record(self.name, self._t0, t1, self.attrs)
+        t = self._tracer
+        if t.enabled or t.ring is not None:
+            t._record(self.name, self._t0, t1, self.attrs)
         return False
 
 
@@ -102,12 +117,13 @@ class Tracer:
     def __init__(self):
         self._lock = threading.Lock()
         self.events: List[Dict[str, Any]] = []
-        self.enabled = False
-        self.annotate = False
+        self.enabled = False          # the Chrome export list
+        # jax.profiler.TraceAnnotation while the profiler sink is on
+        self.annotation: Optional[type] = None
         self.out: Optional[str] = None
         # bounded always-on sink for the flight recorder; None unless
-        # attached.  _active = enabled OR ring attached — the single
-        # attribute the hot path checks.
+        # attached.  _active = any sink on — the single attribute the
+        # hot path checks.
         self.ring: Optional[collections.deque] = None
         self._active = False
         # perf_counter epoch so ts starts near 0 (Perfetto dislikes
@@ -122,7 +138,7 @@ class Tracer:
         return _Span(self, name, attrs)
 
     def instant(self, name: str, **attrs) -> None:
-        if not self._active:
+        if not (self.enabled or self.ring is not None):
             return
         ts = (time.perf_counter() - self._epoch) * 1e6
         ev = {"name": name, "cat": name.split(".")[0], "ph": "i",
@@ -152,19 +168,27 @@ class Tracer:
 
     # -- lifecycle --------------------------------------------------------
     def _refresh_active(self) -> None:
-        self._active = self.enabled or self.ring is not None
+        self._active = (self.enabled or self.ring is not None
+                        or self.annotation is not None)
 
     def enable(self, out: Optional[str] = None,
                annotate: bool = False) -> None:
-        self.enabled = True
-        self.annotate = annotate
+        """Turn the Chrome export list on (written to ``out`` by
+        ``export``/atexit) and, with ``annotate``, the profiler sink;
+        ``annotate`` without ``out`` turns on the profiler sink alone."""
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self.annotation = TraceAnnotation
+        self.enabled = out is not None or not annotate
         if out is not None:
             self.out = out
         self._refresh_active()
 
     def disable(self) -> None:
+        """Turn the export list and the profiler sink off (an attached
+        ring stays)."""
         self.enabled = False
-        self.annotate = False
+        self.annotation = None
         self._refresh_active()
 
     def attach_ring(self, maxlen: int = 2048) -> collections.deque:
@@ -229,10 +253,11 @@ def instant(name: str, **attrs) -> None:
 
 
 def record(name: str, t0: float, t1: float, **attrs) -> None:
-    """Record an already-measured interval; ``t0``/``t1`` must be
+    """Record an already-measured interval in the Chrome export and the
+    ring (the profiler takes live spans only); ``t0``/``t1`` must be
     ``time.perf_counter()`` readings (the tracer's clock)."""
     t = _TRACER
-    if t._active:
+    if t.enabled or t.ring is not None:
         t._record(name, t0, t1, attrs or None)
 
 
@@ -260,8 +285,3 @@ def _export_atexit() -> None:
             t.export()
         except OSError:
             pass
-
-
-_env = os.environ.get("REPRO_TRACE")
-if _env:
-    enable(_env, annotate=bool(os.environ.get("REPRO_TRACE_ANNOTATE")))

@@ -110,6 +110,9 @@ class Request:
     # outputs already generated before a preemption; the resume prompt
     # carries them, sampling continues at this token index
     prior_out: int = 0
+    # time.perf_counter() at submit(); None for a direct admit() and a
+    # preemption's requeue
+    t_submit: Optional[float] = None
 
 
 def sample_tokens(logits, key, temperature: float = 0.0, top_k: int = 0):
@@ -166,7 +169,6 @@ class Server:
         # continuous SLO/anomaly monitor (obs.monitor.Monitor); when
         # None the token hot path pays exactly one attribute check
         self.monitor = monitor
-        self._t_submit: Dict[int, float] = {}   # rid -> submit time
         self._t_last: Dict[int, float] = {}     # rid -> last token time
         self._m_tokens = reg.counter(
             "serve.tokens", help="tokens emitted across all requests")
@@ -203,6 +205,9 @@ class Server:
         self.prompt_len = np.zeros((n,), np.int64)
         self.slot_rid = np.full((n,), -1, np.int64)
         self.slot_seq = np.full((n,), -1, np.int64)  # admission order
+        # the resident request's Request.t_submit (NaN: none), for the
+        # monitor's TTFT
+        self.slot_t_submit = np.full((n,), np.nan)
         self.outputs: Dict[int, List[int]] = {}
         self.finished: Dict[int, str] = {}          # rid -> retire reason
         self.waiting: collections.deque = collections.deque()
@@ -380,18 +385,21 @@ class Server:
         device cache.  The host mutates its mirrors freely between
         dispatches (admission, preemption, speculative rollback) and
         flushes once before the next dispatch."""
-        if self._table_dirty:
-            tbl = jnp.asarray(self.table)
-            if self._table_sh is not None:
-                tbl = jax.device_put(tbl, self._table_sh)
-            self.cache["block_table"] = tbl
-            self._table_dirty = False
-        if self._pos_dirty:
-            pos = jnp.asarray(self.pos.astype(np.int32))
-            if self._pos_sh is not None:
-                pos = jax.device_put(pos, self._pos_sh)
-            self.cache["pos"] = pos
-            self._pos_dirty = False
+        if not (self._table_dirty or self._pos_dirty):
+            return
+        with _span("serve.flush"):
+            if self._table_dirty:
+                tbl = jnp.asarray(self.table)
+                if self._table_sh is not None:
+                    tbl = jax.device_put(tbl, self._table_sh)
+                self.cache["block_table"] = tbl
+                self._table_dirty = False
+            if self._pos_dirty:
+                pos = jnp.asarray(self.pos.astype(np.int32))
+                if self._pos_sh is not None:
+                    pos = jax.device_put(pos, self._pos_sh)
+                self.cache["pos"] = pos
+                self._pos_dirty = False
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt: Sequence[int],
@@ -406,9 +414,8 @@ class Server:
                 f"max_len={self.scfg.max_len} cache")
         rid = self._next_rid
         self._next_rid += 1
-        self.waiting.append(Request(rid, list(prompt), max_new_tokens))
-        if self.monitor is not None:
-            self._t_submit[rid] = time.perf_counter()
+        self.waiting.append(Request(rid, list(prompt), max_new_tokens,
+                                    t_submit=time.perf_counter()))
         return rid
 
     def admit(self, prompt: Sequence[int], slot: int,
@@ -427,8 +434,12 @@ class Server:
 
     def _admit(self, req: Request, slot: int,
                method: str = "chunked") -> List[Tuple]:
-        with _span("serve.admit", rid=req.rid, slot=slot,
-                   prompt_len=len(req.prompt)):
+        attrs = {"rid": req.rid, "slot": slot,
+                 "prompt_len": len(req.prompt)}
+        if req.t_submit is not None:
+            # the wait in this server's own queue, submit to admission
+            attrs["queued_ms"] = (time.perf_counter() - req.t_submit) * 1e3
+        with _span("serve.admit", **attrs):
             return self._admit_impl(req, slot, method)
 
     def _admit_impl(self, req: Request, slot: int,
@@ -449,9 +460,11 @@ class Server:
             _instant("serve.resume", rid=req.rid, slot=slot)
         else:
             _instant("serve.admitted", rid=req.rid, slot=slot)
-        with self._ctx():
+        # the host waits here for the prefill: the first token and the
+        # prompt's last logits come back
+        with _span("serve.sample", rid=req.rid), self._ctx():
             tok = int(self._sample1(logits, req.rid, req.prior_out))
-        self.prefill_logits[slot] = np.asarray(logits)
+            self.prefill_logits[slot] = np.asarray(logits)
         self.active[slot] = True
         self.slot_rid[slot] = req.rid
         self.slot_seq[slot] = next(self._seq)
@@ -464,6 +477,8 @@ class Server:
         # a resumed (preempted) request keeps its accumulated outputs
         self.outputs.setdefault(req.rid, [])
         self._slot_prompt[slot] = [int(x) for x in prompt]
+        self.slot_t_submit[slot] = (np.nan if req.t_submit is None
+                                    else req.t_submit)
         events = [("admit", req.rid, slot)]
         events += self._append(slot, tok)
         return events
@@ -691,15 +706,15 @@ class Server:
             self._table_dirty = True
 
     # -- slot bookkeeping -------------------------------------------------
-    def _observe_token(self, rid: int) -> None:
+    def _observe_token(self, rid: int, slot: int) -> None:
         """Feed the monitor one emitted token: first token since submit
         is TTFT, every later one an ITL.  A preemption gap lands in the
         ITL stream — that is what the client experiences."""
         now = time.perf_counter()
         last = self._t_last.get(rid)
         if last is None:
-            t0 = self._t_submit.pop(rid, None)
-            if t0 is not None:
+            t0 = self.slot_t_submit[slot]
+            if not np.isnan(t0):
                 self.monitor.observe("ttft", now - t0)
         else:
             self.monitor.observe("itl", now - last)
@@ -710,7 +725,7 @@ class Server:
         self.outputs[rid].append(tok)
         self._m_tokens.inc()
         if self.monitor is not None:
-            self._observe_token(rid)
+            self._observe_token(rid, slot)
         self.n_out[slot] += 1
         self.next_tok[slot] = tok
         events: List[Tuple] = [("token", rid, tok)]
@@ -733,7 +748,6 @@ class Server:
         self.slot_rid[slot] = -1
         self.finished[rid] = reason
         self._t_last.pop(rid, None)
-        self._t_submit.pop(rid, None)
         _instant("serve.retire", rid=rid, slot=slot, reason=reason)
         return ("retire", rid, reason)
 
@@ -796,7 +810,8 @@ class Server:
                 jnp.asarray(self.slot_rid, jnp.int32),
                 jnp.asarray(self.n_out, jnp.int32),
                 jnp.asarray(act))
-            toks = np.asarray(toks)
+            with _span("serve.decode.wait"):
+                toks = np.asarray(toks)
         # device array, materialized lazily — only diagnostic consumers
         # (tests, the conformance cell) pay the [slots, vocab] transfer
         self.last_logits = logits
@@ -806,8 +821,9 @@ class Server:
         # only the rows that actually decoded advance (the seed server
         # advanced every slot, so an idle slot's mirror drifted)
         self.pos[act] += 1
-        for slot in slots:
-            events += self._append(slot, int(toks[slot]))
+        with _span("serve.tokens", slots=slots):
+            for slot in slots:
+                events += self._append(slot, int(toks[slot]))
         return events
 
     def spec_once(self) -> List[Tuple]:

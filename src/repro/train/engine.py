@@ -235,8 +235,12 @@ class TrainEngine:
         bspec = {k: self._batch_spec(k) for k in batch_keys}
         n_micro = cfg.microbatches
 
+        # named scopes mark the step's phases in the compiled program's
+        # op metadata (fwd_bwd, grad_sync, optimizer); the backward ops
+        # are the fwd_bwd ones whose op_name holds "transpose("
         def micro_grads(params, mb):
-            loss, grads = jax.value_and_grad(model.loss)(params, mb)
+            with jax.named_scope("fwd_bwd"):
+                loss, grads = jax.value_and_grad(model.loss)(params, mb)
             return loss, grads
 
         def step_fn(state, batch):
@@ -274,38 +278,40 @@ class TrainEngine:
                     lambda a: a / n_micro, acc)
                 loss = lsum / n_micro
 
-            grads, new_err = self._sync_grads(grads, state.get("err"),
-                                              grad_specs)
-            ref = state["master"] if cfg.master_fp32 else params
-            new_ref, new_opt, gnorm = apply_updates(ref, grads,
-                                                    state["opt"],
-                                                    cfg.optim)
-            new_state = dict(state)
-            new_state["opt"] = jax.tree_util.tree_map(
-                lambda x, sp: self._constrain(x, sp) if sp is not None
-                else x, new_opt, pspecs["opt"])
-            if cfg.master_fp32:
-                new_state["master"] = jax.tree_util.tree_map(
-                    lambda x, sp: self._constrain(x, sp),
-                    new_ref, pspecs["master"])
-                # cast-down to the bf16 compute weight; after a sharded
-                # (ZeRO) update this is the all-gather that moves bf16,
-                # not f32 — the graph extension prices exactly this.  The
-                # intermediate constraint pins the convert *before* the
-                # gather (GSPMD otherwise happily all-gathers the f32
-                # master and converts afterwards, doubling wire bytes).
-                def cast_down(m, p, msp, psp):
-                    y = self._constrain(m.astype(p.dtype), msp)
-                    return self._constrain(y, psp)
+            with jax.named_scope("grad_sync"):
+                grads, new_err = self._sync_grads(
+                    grads, state.get("err"), grad_specs)
+            with jax.named_scope("optimizer"):
+                ref = state["master"] if cfg.master_fp32 else params
+                new_ref, new_opt, gnorm = apply_updates(ref, grads,
+                                                        state["opt"],
+                                                        cfg.optim)
+                new_state = dict(state)
+                new_state["opt"] = jax.tree_util.tree_map(
+                    lambda x, sp: self._constrain(x, sp) if sp is not None
+                    else x, new_opt, pspecs["opt"])
+                if cfg.master_fp32:
+                    new_state["master"] = jax.tree_util.tree_map(
+                        lambda x, sp: self._constrain(x, sp),
+                        new_ref, pspecs["master"])
+                    # cast-down to the bf16 compute weight; after a sharded
+                    # (ZeRO) update this is the all-gather that moves bf16,
+                    # not f32 — the graph extension prices exactly this.  The
+                    # intermediate constraint pins the convert *before* the
+                    # gather (GSPMD otherwise happily all-gathers the f32
+                    # master and converts afterwards, doubling wire bytes).
+                    def cast_down(m, p, msp, psp):
+                        y = self._constrain(m.astype(p.dtype), msp)
+                        return self._constrain(y, psp)
 
-                new_params = jax.tree_util.tree_map(
-                    cast_down, new_state["master"], params,
-                    pspecs["master"], pspecs["params"])
-            else:
-                new_params = jax.tree_util.tree_map(
-                    lambda x, sp: self._constrain(x, sp),
-                    new_ref, pspecs["params"])
-            new_state["params"] = new_params
+                    new_params = jax.tree_util.tree_map(
+                        cast_down, new_state["master"], params,
+                        pspecs["master"], pspecs["params"])
+                else:
+                    new_params = jax.tree_util.tree_map(
+                        lambda x, sp: self._constrain(x, sp),
+                        new_ref, pspecs["params"])
+                new_state["params"] = new_params
             if new_err is not None:
                 new_state["err"] = jax.tree_util.tree_map(
                     lambda x, sp: self._constrain(x, sp),

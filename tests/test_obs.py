@@ -133,6 +133,44 @@ class TestTracing:
         assert doc["displayTimeUnit"] == "ms"
         assert validate_trace(doc) == []
 
+    def test_profiler_sink_puts_attrs_on_the_profiler_clock(
+            self, clean_tracer, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        t = clean_tracer
+        t.enable(annotate=True)
+        jax.profiler.start_trace(str(tmp_path))
+        with tracing.span("serve.admit", rid=7, queued_ms=3.5,
+                          slots=[0, 4, 9]) as sp:
+            sp.set(late=1)          # after opening: Chrome export only
+            with tracing.span("serve.sample"):
+                pass
+        jax.profiler.stop_trace()
+        t.disable()
+        assert t.events == []       # the profiler sink keeps no list
+        path = next(p for p in tmp_path.rglob("*.xplane.pb"))
+        host = {ev.name: dict(ev.stats)
+                for plane in ProfileData.from_file(str(path)).planes
+                for line in plane.lines for ev in line.events
+                if ev.name.startswith("serve.")}
+        assert host["serve.admit"] == {"rid": 7, "queued_ms": 3.5,
+                                       "n_slots": 3}
+        assert host["serve.sample"] == {}
+        assert tracing.span("serve.admit", rid=1) is tracing.NULL_SPAN
+
+    def test_sinks_combine_and_disable_together(self, clean_tracer):
+        t = clean_tracer
+        t.enable(annotate=True)
+        assert not t.enabled and t.annotation is not None
+        t.enable("x.trace.json", annotate=True)
+        assert t.enabled and t.annotation is not None
+        with tracing.span("train.step", step=3):
+            pass
+        assert [e["args"] for e in t.events] == [{"step": 3}]
+        t.disable()
+        assert t.annotation is None and not t._active
+        assert tracing.span("train.step") is tracing.NULL_SPAN
+
 
 # ---------------------------------------------------------------- metrics --
 
