@@ -252,7 +252,10 @@ def _decode_tile(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scr, **kw):
+def _decode_kernel(len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref, *scr,
+                   **kw):
+    # the BlockSpec index_map already used lyr_ref to route (k_ref,
+    # v_ref) at the layer's tiles of the stacked cache
     bb = pl.program_id(0)
     _decode_tile(q_ref, k_ref, v_ref, o_ref, *scr,
                  k_start=pl.program_id(1) * k_ref.shape[0],
@@ -276,15 +279,21 @@ def _decode_scratch(kv, g, hd):
             pltpu.VMEM((kv, g, hd), jnp.float32)]
 
 
-def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
-                           scale=None, block_k=128, interpret=False):
-    """One decode step: q [B, H, hd] against the slot cache
-    [B, S, KV, hd] with per-slot valid ``lengths`` [B] (the serving
-    engine's slot semantics: positions >= length are dead, an optional
-    sliding ``window`` keeps only the last ``window`` of them).  GQA is
-    blocked like attend_cache: head h belongs to kv group h // g."""
+def flash_attention_decode(q, k_cache, v_cache, lengths, layer, *,
+                           window=None, scale=None, block_k=128,
+                           interpret=False):
+    """One decode step of one layer: q [B, H, hd] against that layer of
+    the stacked slot cache [L, B, S, KV, hd], with per-slot valid
+    ``lengths`` [B] (the serving engine's slot semantics: positions >=
+    length are dead, an optional sliding ``window`` keeps only the last
+    ``window`` of them).  ``layer`` (int32 scalar) rides scalar prefetch
+    next to ``lengths``, so the BlockSpec index_map reads the layer's
+    tiles straight out of the stacked buffer and no per-layer slice is
+    ever made in HBM; a caller holding one layer passes ``cache[None]``
+    and layer 0.  GQA is blocked like attend_cache: head h belongs to kv
+    group h // g."""
     b, h, hd = q.shape
-    _, s, kv, _ = k_cache.shape
+    _, _, s, kv, _ = k_cache.shape
     _check_gqa(h, kv)
     g = h // kv
     scale = scale if scale is not None else hd ** -0.5
@@ -293,17 +302,18 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
     qg = q.reshape(b, kv, g, hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((None, kv, g, hd), lambda bb, ki, L: (bb, 0, 0, 0)),
-            pl.BlockSpec((None, block_k, kv, hd),
-                         lambda bb, ki, L: (bb, ki, 0, 0)),
-            pl.BlockSpec((None, block_k, kv, hd),
-                         lambda bb, ki, L: (bb, ki, 0, 0)),
+            pl.BlockSpec((None, kv, g, hd),
+                         lambda bb, ki, L, lyr: (bb, 0, 0, 0)),
+            pl.BlockSpec((None, None, block_k, kv, hd),
+                         lambda bb, ki, L, lyr: (lyr[0], bb, ki, 0, 0)),
+            pl.BlockSpec((None, None, block_k, kv, hd),
+                         lambda bb, ki, L, lyr: (lyr[0], bb, ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, kv, g, hd),
-                               lambda bb, ki, L: (bb, 0, 0, 0)),
+                               lambda bb, ki, L, lyr: (bb, 0, 0, 0)),
         scratch_shapes=_decode_scratch(kv, g, hd),
     )
     o = pl.pallas_call(
@@ -313,7 +323,8 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *, window=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(lengths, jnp.int32), qg, k_cache, v_cache)
+    )(jnp.asarray(lengths, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_cache, v_cache)
     return o.reshape(b, h, hd)
 
 

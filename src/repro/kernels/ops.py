@@ -84,12 +84,14 @@ def flash_attention_offset(q, k, v, q_offset, *, causal: bool = True,
     return o
 
 
-def flash_attention_decode(q, k_cache, v_cache, lengths, *,
+def flash_attention_decode(q, k_cache, v_cache, lengths, layer, *,
                            window: Optional[int] = None,
                            scale: Optional[float] = None):
-    """One decode step against the serving engine's slot cache (per-slot
-    ``lengths``, optional sliding window).  Forward-only."""
-    return fa.flash_attention_decode(q, k_cache, v_cache, lengths,
+    """One decode step of layer ``layer`` against the serving engine's
+    stacked slot cache [L, B, S, KV, hd] (per-slot ``lengths``, optional
+    sliding window); the kernel reads the layer's tiles in place.
+    Forward-only."""
+    return fa.flash_attention_decode(q, k_cache, v_cache, lengths, layer,
                                      window=window, scale=scale,
                                      interpret=_default_interpret())
 

@@ -96,27 +96,30 @@ def _axes_degree(mesh, entry) -> int:
     return d
 
 
-def attend_cache_pallas(q, k_cache, v_cache, length, *,
+def attend_cache_pallas(q, k_cache, v_cache, length, layer, *,
                         window: Optional[int] = None,
                         scale: Optional[float] = None,
                         mesh=None, plan=None):
-    """Pallas decode kernel path.  With a mesh + plan the kernel runs
-    under shard_map with the plan's solved kv_cache sharding (batch and
-    kv_heads dims); a seq_kv cut — which would split the softmax — or a
-    non-dividing degree falls back to the XLA path rather than computing
-    a partial reduction."""
+    """Pallas decode kernel path over layer ``layer`` of the stacked
+    caches [L, B, S, KV, hd].  With a mesh + plan the kernel runs under
+    shard_map with the plan's solved kv_cache sharding (batch and
+    kv_heads dims; the layer axis and the index stay whole); a seq_kv
+    cut — which would split the softmax — or a non-dividing degree
+    falls back to the XLA path rather than computing a partial
+    reduction."""
     from ..kernels import ops as kops
 
     if mesh is None or plan is None:
         return kops.flash_attention_decode(q, k_cache, v_cache, length,
-                                           window=window, scale=scale)
+                                           layer, window=window,
+                                           scale=scale)
 
     from functools import partial
 
     from jax.sharding import PartitionSpec as P
 
     b, h, hd = q.shape
-    _, _, kv, _ = k_cache.shape
+    kv = k_cache.shape[3]
     cspec = _spec_entries(
         plan.pspec("kv_cache", ("batch", "seq_kv", "kv_heads", "hd")), 4)
     bs, ss, hs, ds = cspec
@@ -126,30 +129,34 @@ def attend_cache_pallas(q, k_cache, v_cache, length, *,
           and (hs is None or kv % _axes_degree(mesh, hs) == 0
                and h % _axes_degree(mesh, hs) == 0))
     if not ok:
-        return attend_cache(q, k_cache, v_cache, length,
+        return attend_cache(q, k_cache, v_cache, length, layer=layer,
                             window=window, scale=scale)
     fn = jax.shard_map(
         partial(kops.flash_attention_decode, window=window, scale=scale),
         mesh=mesh,
-        in_specs=(P(bs, hs, None), P(bs, None, hs, None),
-                  P(bs, None, hs, None), P(bs)),
+        in_specs=(P(bs, hs, None), P(None, bs, None, hs, None),
+                  P(None, bs, None, hs, None), P(bs), P()),
         out_specs=P(bs, hs, None),
         check_vma=False)
-    return fn(q, k_cache, v_cache, length)
+    return fn(q, k_cache, v_cache, length, layer)
 
 
-def attend_cache(q, k_cache, v_cache, length, *,
+def attend_cache(q, k_cache, v_cache, length, *, layer=None,
                  window: Optional[int] = None,
                  scale: Optional[float] = None,
                  impl: str = "xla", mesh=None, plan=None):
-    """Decode attention: q [B, H, hd] against caches [B, S, KV, hd];
-    ``length`` [B] = number of valid cache entries (new token already
-    written at position length-1).  impl="pallas" routes through the
-    fused decode kernel (shard_map-wrapped when mesh/plan are given)."""
+    """Decode attention: q [B, H, hd] against caches [B, S, KV, hd], or
+    against layer ``layer`` of stacked caches [L, B, S, KV, hd] (the
+    decode step's, read in place); ``length`` [B] = number of valid
+    cache entries (new token already written at position length-1).
+    impl="pallas" routes a stacked cache through the fused decode
+    kernel (shard_map-wrapped when mesh/plan are given)."""
     if impl == "pallas":
-        return attend_cache_pallas(q, k_cache, v_cache, length,
+        return attend_cache_pallas(q, k_cache, v_cache, length, layer,
                                    window=window, scale=scale,
                                    mesh=mesh, plan=plan)
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     b, h, hd = q.shape
     _, s, kv, _ = k_cache.shape
     g = h // kv

@@ -195,7 +195,8 @@ class LM:
 
     def _fold(self, body, x, stacked):
         """scan-or-unroll over the leading layer axis; body returns
-        (x, per-layer-out)."""
+        (carry, per-layer-out).  The carry ``x`` may be any pytree (the
+        decode step carries (activations, stacked KV cache))."""
         if self.layer_loop == "scan":
             return jax.lax.scan(body, x, stacked)
         n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
@@ -421,11 +422,16 @@ class LM:
             },
         }
 
-    def _attn_decode(self, p, x, kv_cache, pos, cfg, win, active=None):
-        """x: [B, D]; kv_cache: {"k","v"} [B, S, KV, hd] for ONE layer.
-        ``active`` [B] bool (optional): rows marked inactive drop their
-        K/V write (index pushed out of range, scatter mode="drop") so an
-        idle slot's cache row cannot be disturbed between requests."""
+    def _attn_decode(self, p, x, kv, l, pos, cfg, win, active=None):
+        """x: [B, D]; kv: {"k","v"} the stacked [L, B, S, KV, hd] cache,
+        carried through the layer scan; ``l``: this layer's index.  The
+        token's K/V lands with one scatter at [l, row, slot] of the
+        carried buffer (in place inside the loop), and attention reads
+        layer ``l`` where it lies — no per-layer slice, restack or copy
+        of the cache.  ``active`` [B] bool (optional): rows marked
+        inactive drop their K/V write (index pushed out of range,
+        scatter mode="drop") so an idle slot's cache row cannot be
+        disturbed between requests."""
         b, d = x.shape
         hd, h, kvh = cfg.hd, cfg.n_heads, cfg.n_kv_heads
         q = x @ p["wq"]
@@ -438,20 +444,19 @@ class LM:
         k = rope(k.reshape(b, 1, kvh, hd), pos[:, None],
                  cfg.rope_theta)[:, 0]
         v = v.reshape(b, kvh, hd)
-        S = kv_cache["k"].shape[1]
+        S = kv["k"].shape[2]
         slot = pos % S if win else pos
         if active is not None:
             slot = jnp.where(active, slot, S)      # OOB -> dropped
+        rows = jnp.arange(b)
         with jax.named_scope("kv_write"):
-            kc = jax.vmap(
-                lambda c, i, val: c.at[i].set(val, mode="drop"))(
-                kv_cache["k"], slot, k.astype(jnp.bfloat16))
-            vc = jax.vmap(
-                lambda c, i, val: c.at[i].set(val, mode="drop"))(
-                kv_cache["v"], slot, v.astype(jnp.bfloat16))
-        length = jnp.minimum(pos + 1, kc.shape[1])
+            kc = kv["k"].at[l, rows, slot].set(k.astype(jnp.bfloat16),
+                                               mode="drop")
+            vc = kv["v"].at[l, rows, slot].set(v.astype(jnp.bfloat16),
+                                               mode="drop")
+        length = jnp.minimum(pos + 1, S)
         with jax.named_scope("attn"):
-            o = attend_cache(q, kc, vc, length, window=None,
+            o = attend_cache(q, kc, vc, length, layer=l, window=None,
                              impl=self.attn_impl, mesh=self.mesh,
                              plan=self.plan)
         return (o.reshape(b, h * hd) @ p["wo"],
@@ -535,8 +540,9 @@ class LM:
                 lambda a: a.reshape((n_shared, period) + a.shape[1:]),
                 cache["mamba"])
 
-            def outer(x, inp):
-                pgrp, sgrp, kvi = inp
+            def outer(carry, inp):
+                x, kv = carry
+                pgrp, sgrp, l = inp
 
                 def inner(xc, pin):
                     p, st = pin
@@ -547,16 +553,17 @@ class LM:
 
                 x, st_new = jax.lax.scan(inner, x, (pgrp, sgrp))
                 ps = params["shared"]
-                h, kv_new = self._attn_decode(
+                h, kv = self._attn_decode(
                     ps["attn"], rms_norm(x, ps["ln1"], cfg.norm_eps),
-                    kvi, pos, cfg, win=True, active=active)
+                    kv, l, pos, cfg, win=True, active=active)
                 x = x + h
                 x = x + _mlp_forward(ps["mlp"],
                                      rms_norm(x, ps["ln2"], cfg.norm_eps))
-                return x, (st_new, kv_new)
+                return (x, kv), st_new
 
-            x, (mstate_new, kv_new) = self._fold(
-                outer, x, (mamba_groups, mstate, cache["shared"]))
+            (x, kv_new), mstate_new = self._fold(
+                outer, (x, cache["shared"]),
+                (mamba_groups, mstate, jnp.arange(n_shared)))
             new_cache["mamba"] = jax.tree_util.tree_map(
                 lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]),
                 mstate_new)
@@ -608,11 +615,15 @@ class LM:
                                      (params["layers"], cache["pages"]))
             new_cache["pages"] = pool_new
         else:
-            def body(x, inp):
-                p, kvi = inp
-                h, kv_new = self._attn_decode(
+            # the cache rides the carry, not xs/ys: scanning it would
+            # slice every layer out, restack the outputs and copy the
+            # result — three passes over the whole cache per step
+            def body(carry, inp):
+                x, kv = carry
+                p, l = inp
+                h, kv = self._attn_decode(
                     p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps),
-                    kvi, pos, cfg, win=cfg.swa_window is not None,
+                    kv, l, pos, cfg, win=cfg.swa_window is not None,
                     active=active)
                 x = x + h
                 xn = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -621,11 +632,11 @@ class LM:
                     y = y[:, 0]
                 else:
                     y = _mlp_forward(p["mlp"], xn)
-                return x + y, kv_new
+                return (x + y, kv), None
 
-            x, kv_new = self._fold(body, x,
-                                   (params["layers"], cache["kv"]))
-            new_cache["kv"] = kv_new
+            (x, new_cache["kv"]), _ = self._fold(
+                body, (x, cache["kv"]),
+                (params["layers"], jnp.arange(cfg.n_layers)))
 
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         if active is None:

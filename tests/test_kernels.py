@@ -212,10 +212,10 @@ class TestGQAParity:
     def test_decode_indivisible_heads_raise(self):
         key = jax.random.PRNGKey(12)
         q = jax.random.normal(key, (2, 4, 32))
-        kc = jax.random.normal(key, (2, 64, 3, 32))
+        kc = jax.random.normal(key, (1, 2, 64, 3, 32))
         lengths = jnp.full((2,), 16, jnp.int32)
         with pytest.raises(ValueError, match="divisible"):
-            ops.flash_attention_decode(q, kc, kc, lengths)
+            ops.flash_attention_decode(q, kc, kc, lengths, 0)
 
 
 class TestDecodeKernel:
@@ -226,31 +226,45 @@ class TestDecodeKernel:
         (4, 2, None), (4, 4, None), (8, 2, 16), (2, 1, 24),
     ])
     def test_matches_attend_cache(self, h, kv, window):
+        """The kernel reads layer ``l`` of a stacked [L, B, S, KV, hd]
+        cache in place; each layer must equal attend_cache on that
+        layer's slice.  A row of length 0 reads nothing and comes out
+        zero (attend_cache has no defined answer there: an all-masked
+        softmax averages every position)."""
         from repro.models.attention import attend_cache
-        b, S, hd = 4, 96, 32
+        n_layers, b, S, hd = 3, 5, 96, 32
         key = jax.random.PRNGKey(13)
         k1, k2, k3 = jax.random.split(key, 3)
         q = jax.random.normal(k1, (b, h, hd))
-        kc = jax.random.normal(k2, (b, S, kv, hd))
-        vc = jax.random.normal(k3, (b, S, kv, hd))
-        lengths = jnp.array([1, 17, 64, 96], jnp.int32)
-        o_x = attend_cache(q, kc, vc, lengths, window=window,
-                           impl="xla")
-        o_p = ops.flash_attention_decode(q, kc, vc, lengths,
-                                         window=window)
-        np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_x),
-                                   atol=2e-5, rtol=2e-5)
+        kc = jax.random.normal(k2, (n_layers, b, S, kv, hd))
+        vc = jax.random.normal(k3, (n_layers, b, S, kv, hd))
+        lengths = jnp.array([1, 17, 0, 64, 96], jnp.int32)
+        live = np.asarray(lengths) > 0
+        for layer in range(n_layers):
+            o_x = attend_cache(q, kc[layer], vc[layer], lengths,
+                               window=window, impl="xla")
+            o_p = ops.flash_attention_decode(q, kc, vc, lengths,
+                                             jnp.int32(layer),
+                                             window=window)
+            np.testing.assert_allclose(np.asarray(o_p)[live],
+                                       np.asarray(o_x)[live],
+                                       atol=2e-5, rtol=2e-5)
+            assert not np.asarray(o_p)[~live].any()
+            o_s = attend_cache(q, kc, vc, lengths, layer=layer,
+                               window=window, impl="xla")
+            np.testing.assert_array_equal(np.asarray(o_s),
+                                          np.asarray(o_x))
 
     def test_attend_cache_pallas_dispatch(self):
         from repro.models.attention import attend_cache
         b, S, h, kv, hd = 2, 64, 4, 2, 32
         key = jax.random.PRNGKey(14)
         q = jax.random.normal(key, (b, h, hd))
-        kc = jax.random.normal(key, (b, S, kv, hd))
-        vc = jax.random.normal(key, (b, S, kv, hd))
+        kc = jax.random.normal(key, (2, b, S, kv, hd))
+        vc = jax.random.normal(key, (2, b, S, kv, hd))
         lengths = jnp.array([5, 33], jnp.int32)
-        o_x = attend_cache(q, kc, vc, lengths, impl="xla")
-        o_p = attend_cache(q, kc, vc, lengths, impl="pallas")
+        o_x = attend_cache(q, kc, vc, lengths, layer=1, impl="xla")
+        o_p = attend_cache(q, kc, vc, lengths, layer=1, impl="pallas")
         np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_x),
                                    atol=2e-5, rtol=2e-5)
 

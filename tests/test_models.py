@@ -1,5 +1,7 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 forward + one train-grad + one decode step on CPU; shapes + finiteness."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -142,3 +144,100 @@ class TestConfigExactness:
             == pytest.approx(3.97e9, rel=0.2)
         assert get_arch("phi3.5-moe-42b-a6.6b").active_param_count() \
             == pytest.approx(6.6e9, rel=0.2)
+
+
+def _kv_caches(cfg, cache):
+    """The attention KV leaves of a decode cache: the dense families'
+    stacked "kv", the hybrid's shared-block "shared"."""
+    return cache["shared"] if cfg.family == "hybrid" else cache["kv"]
+
+
+def _scans(jaxpr):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "scan":
+            yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _scans(sub)
+
+
+# dense, ring-buffer SWA, MoE, and the hybrid's shared attention block
+KV_ARCHS = ["qwen2-1.5b", "h2o-danube-3-4b", "moonshot-v1-16b-a3b",
+            "zamba2-2.7b"]
+
+
+class TestDecodeCacheInPlace:
+    """The decode step carries the stacked KV cache through its layer
+    scan: nothing slices a layer out of it, restacks it or copies it."""
+
+    @pytest.mark.parametrize("arch", KV_ARCHS)
+    def test_cache_rides_the_carry(self, arch, key):
+        cfg = get_arch(arch).reduced()
+        m = LM(cfg)
+        params = m.init(key)
+        cache = m.init_cache(B, 32)
+        kv = _kv_caches(cfg, cache)
+        stacked = kv["k"].shape
+        per_layer = stacked[1:]
+        jaxpr = jax.make_jaxpr(m.decode_step)(
+            params, cache, jnp.zeros((B,), jnp.int32),
+            jnp.array([True, False])).jaxpr
+        scans = list(_scans(jaxpr))
+        assert scans
+        carried = 0
+        for e in scans:
+            nc, nk = e.params["num_carry"], e.params["num_consts"]
+            xs = e.invars[nk + nc:]
+            ys = e.outvars[nc:]
+            body_ys = e.params["jaxpr"].jaxpr.outvars[nc:]
+            assert all(v.aval.shape not in (stacked, per_layer)
+                       for v in list(xs) + list(ys) + list(body_ys)), arch
+            carried += sum(v.aval.shape == stacked
+                           for v in e.outvars[:nc])
+        assert carried >= 2                         # k and v
+
+    @pytest.mark.parametrize("arch", KV_ARCHS)
+    def test_scan_and_unrolled_bit_equal(self, arch, key):
+        # float32 weights (the cache stays bf16): XLA's CPU backend
+        # rounds bf16 intermediates differently in a while body than in
+        # straight-line code, which would hide what this compares — the
+        # two loop forms threading the same carried cache
+        cfg = dataclasses.replace(get_arch(arch).reduced(), dtype="float32")
+        scan = LM(cfg)
+        unrolled = LM(cfg, layer_loop="unrolled")
+        params = scan.init(key)
+        toks = jax.random.randint(key, (B, 5), 0, cfg.vocab)
+        active = jnp.array([True, False])
+        steps = [jax.jit(scan.decode_step), jax.jit(unrolled.decode_step)]
+        caches = [scan.init_cache(B, 32), unrolled.init_cache(B, 32)]
+        for i in range(toks.shape[1]):
+            (la, caches[0]), (lb, caches[1]) = (
+                step(params, c, toks[:, i], active if i % 2 else None)
+                for step, c in zip(steps, caches))
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        for a, b in zip(jax.tree_util.tree_leaves(caches[0]),
+                        jax.tree_util.tree_leaves(caches[1])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("arch", KV_ARCHS)
+    def test_inactive_row_untouched(self, arch, key):
+        cfg = get_arch(arch).reduced()
+        m = LM(cfg)
+        params = m.init(key)
+        cache = m.init_cache(B, 32)
+        name = "shared" if cfg.family == "hybrid" else "kv"
+        # a row full of noise, at a position inside the cache
+        cache[name] = jax.tree_util.tree_map(
+            lambda a: jax.random.normal(key, a.shape).astype(a.dtype),
+            cache[name])
+        cache["pos"] = jnp.array([5, 7], jnp.int32)
+        before = jax.tree_util.tree_map(np.asarray, cache[name])
+        _, new = jax.jit(m.decode_step)(
+            params, cache, jnp.array([3, 4], jnp.int32),
+            jnp.array([True, False]))
+        for leaf in ("k", "v"):
+            got = np.asarray(new[name][leaf])
+            np.testing.assert_array_equal(got[:, 1], before[leaf][:, 1])
+            # the active row wrote its token at its position, only there
+            changed = (got[:, 0] != before[leaf][:, 0]).any(axis=(0, 2, 3))
+            assert changed.nonzero()[0].tolist() == [5]
+        assert np.asarray(new["pos"]).tolist() == [6, 7]

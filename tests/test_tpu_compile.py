@@ -29,6 +29,7 @@ HEADS = [
 ]
 SEQ = 2048
 SLOTS = 8
+LAYERS = 4
 
 
 @pytest.fixture(scope="module")
@@ -97,12 +98,13 @@ def test_flash_backward(one_chip, h, kv, hd, window):
 @pytest.mark.parametrize("h,kv,hd,window", HEADS)
 def test_decode(one_chip, h, kv, hd, window):
     q = _spec(one_chip, (SLOTS, h, hd))
-    cache = _spec(one_chip, (SLOTS, SEQ, kv, hd))
+    cache = _spec(one_chip, (LAYERS, SLOTS, SEQ, kv, hd))
     lengths = _spec(one_chip, (SLOTS,), jnp.int32)
+    layer = _spec(one_chip, (), jnp.int32)
     txt = _compile_text(
-        lambda q, kc, vc, n: fa.flash_attention_decode(q, kc, vc, n,
-                                                       window=window),
-        q, cache, cache, lengths)
+        lambda q, kc, vc, n, l: fa.flash_attention_decode(
+            q, kc, vc, n, l, window=window),
+        q, cache, cache, lengths, layer)
     assert "tpu_custom_call" in txt
 
 
@@ -128,3 +130,49 @@ def test_ssd_chunk_scan(one_chip):
         _spec(one_chip, (b, s, h, p), f32), _spec(one_chip, (b, s, h), f32),
         _spec(one_chip, (b, s, n), f32), _spec(one_chip, (b, s, n), f32))
     assert "tpu_custom_call" in txt
+
+
+def test_decode_step_keeps_cache_in_place(one_chip, monkeypatch):
+    """The server's decode step, compiled for the chip, carries the
+    stacked KV cache through its layer loop: no copy, dynamic-slice or
+    dynamic-update-slice outputs the cache's shape or one layer's slice
+    of it (scanning the cache as the loop's input and output made three
+    such passes over the whole cache per step)."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.models.model import LM
+    from repro.runtime.serve import ServeConfig, Server
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    ops._default_interpret.cache_clear()
+    try:
+        # qwen2-1.5b's cache geometry (2 kv heads x 128) on a narrow
+        # model: the chip's compiler lays the cache out by these two
+        # minor dims, and a cache this size stays in HBM
+        cfg = dataclasses.replace(get_arch("qwen2-1.5b").reduced(),
+                                  n_kv_heads=2, head_dim=128)
+        model = LM(cfg)
+        srv = Server(model, model.init(jax.random.PRNGKey(0)),
+                     ServeConfig(slots=SLOTS, max_len=512,
+                                 attn_impl="pallas"))
+        state = jax.tree_util.tree_map(
+            lambda a: _spec(one_chip, a.shape, a.dtype),
+            (srv.params, srv.cache))
+        i32 = _spec(one_chip, (SLOTS,), jnp.int32)
+        txt = srv._decode.lower(
+            *state, i32, i32, i32,
+            _spec(one_chip, (SLOTS,), jnp.bool_)).compile().as_text()
+    finally:
+        ops._default_interpret.cache_clear()
+    assert "tpu_custom_call" in txt
+    stacked = srv.cache["kv"]["k"].shape
+    dims = {",".join(map(str, s)) for s in
+            (stacked, stacked[1:], (1,) + stacked[1:])}
+    movers = re.findall(
+        r"= bf16\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)"
+        r"\(", txt)
+    assert movers                      # the pattern reads this HLO
+    assert not [m for m in movers if m[0] in dims], movers
